@@ -179,9 +179,6 @@ class TraceConfig:
     # dispatch, keyed serve/<prog>|B=..|S=..|... — the per-bucket step
     # latency signal the ROADMAP autotuner-drift item reads)
     step_times: bool = True
-    # wrap kernels/ops.py grouped_matmul/attention in jax.named_scope so
-    # device profiles (jax.profiler) carry kernel-level names
-    annotate_kernels: bool = False
 
 
 @dataclass(frozen=True)

@@ -277,6 +277,7 @@ def grouped_matmul(
         ),
         out_shape=jax.ShapeDtypeStruct((t_pad, n_pad), out_dtype),
         interpret=interpret,
+        name="grouped_matmul",
     )(g_ids, m_ids, row_start, row_end, *args)
 
     return out[:T, :Dout]

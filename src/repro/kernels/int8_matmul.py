@@ -99,5 +99,6 @@ def int8_matmul(
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=interpret,
+        name="int8_matmul",
     )(*args)
     return out[:M, :N]

@@ -31,37 +31,6 @@ def kernel_mode() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
-# -- kernel trace annotations (DESIGN.md section 11) -------------------------
-#
-# With annotations on, the hot dispatch wrappers wrap their bodies in
-# jax.named_scope so device profiles (jax.profiler traces) carry kernel-level
-# names with their shape signatures. named_scope is trace-time metadata: it
-# costs nothing at execution time, and with the flag off (the default) the
-# wrappers don't even build the scope name — serving without profiling pays
-# one module-global read. TraceConfig.annotate_kernels flips this via
-# serving/trace.make_tracer.
-
-_ANNOTATE = False
-
-
-def set_kernel_annotations(on: bool = True) -> None:
-    """Enable/disable named_scope annotations on the kernel wrappers."""
-    global _ANNOTATE
-    _ANNOTATE = bool(on)
-
-
-def kernel_annotations_enabled() -> bool:
-    return _ANNOTATE
-
-
-def _scope(name_fn):
-    """named_scope from a lazy name thunk — the f-string only renders when
-    annotations are on (the disabled path allocates nothing)."""
-    if not _ANNOTATE:
-        return contextlib.nullcontext()
-    return jax.named_scope(name_fn())
-
-
 # Mesh over which the operands of kernel calls are replicated while a
 # forward is traced (the expert-parallel mesh: distributed/expert_parallel
 # .use_ep_mesh installs it). XLA's partitioner cannot split a Mosaic kernel,
@@ -113,33 +82,6 @@ def attention(
     kv_segment_ids: Optional[jnp.ndarray] = None,  # [B, Sk]
 ) -> jnp.ndarray:
     """Streaming attention; GQA-native (k/v carry KVH heads)."""
-    with _scope(lambda: (
-            f"attention[B={q.shape[0]},H={q.shape[1]},Sq={q.shape[2]},"
-            f"Sk={k.shape[2]},q{quant_bits}]")):
-        return _attention(
-            q, k, v, causal=causal, q_offset=q_offset,
-            quant_bits=quant_bits, logit_softcap=logit_softcap,
-            local_window=local_window, k_scale=k_scale, v_scale=v_scale,
-            kv_valid_len=kv_valid_len, q_segment_ids=q_segment_ids,
-            kv_segment_ids=kv_segment_ids)
-
-
-def _attention(
-    q: jnp.ndarray,
-    k: jnp.ndarray,
-    v: jnp.ndarray,
-    *,
-    causal: bool,
-    q_offset,
-    quant_bits: int,
-    logit_softcap: float,
-    local_window: int,
-    k_scale: Optional[jnp.ndarray],
-    v_scale: Optional[jnp.ndarray],
-    kv_valid_len: Optional[jnp.ndarray],
-    q_segment_ids: Optional[jnp.ndarray],
-    kv_segment_ids: Optional[jnp.ndarray],
-) -> jnp.ndarray:
     mode = kernel_mode()
     if mode in ("pallas", "interpret"):
         from repro.kernels import autotune
@@ -195,22 +137,6 @@ def grouped_matmul(
     lands once on the accumulator — the full-precision expert weights are
     never materialized outside the kernel.
     """
-    with _scope(lambda: (
-            f"grouped_matmul[T={x.shape[0]},G={w.shape[0]},"
-            f"Din={w.shape[1]},Dout={w.shape[2]},{w.dtype}]")):
-        return _grouped_matmul(x, w, group_sizes, w_scale=w_scale,
-                               a_scale=a_scale, a_bits=a_bits)
-
-
-def _grouped_matmul(
-    x: jnp.ndarray,
-    w: jnp.ndarray,
-    group_sizes: jnp.ndarray,
-    *,
-    w_scale: Optional[jnp.ndarray],
-    a_scale: Optional[jnp.ndarray],
-    a_bits: int,
-) -> jnp.ndarray:
     mode = kernel_mode()
     int8_w = w.dtype == jnp.int8
     int4_w = w.dtype == jnp.uint8  # nibble-packed int4 stack (W4A8)

@@ -361,6 +361,7 @@ def streaming_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, sq_pad, hd), q.dtype),
         interpret=interpret,
+        name="streaming_attention",
     )(meta, valid, *args)
 
     return out[:, :, :Sq, :].transpose(0, 2, 1, 3)

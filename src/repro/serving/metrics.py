@@ -384,12 +384,12 @@ class EngineMetrics:
 
 
 def _occupancy_of(tokens: np.ndarray) -> List[float]:
-    """Normalized + rounded occupancy — the one formula both the replica
-    and the aggregate snapshot fields render with."""
+    """Normalized occupancy (shares that sum to 1) — the one formula both
+    the replica and the aggregate snapshot fields render with."""
     total = tokens.sum()
     if total == 0:
         return [0.0] * int(tokens.size)
-    return [round(float(x), 6) for x in tokens / float(total)]
+    return [float(x) for x in tokens / float(total)]
 
 
 def _occupancy_stats(tokens: np.ndarray) -> Optional[dict]:

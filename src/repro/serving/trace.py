@@ -38,14 +38,29 @@ replica, one *thread* track per request plus thread 0 for the engine's
 per-program step spans. ``validate_chrome_trace`` and
 ``validate_request_timelines`` are the well-formedness checks CI runs on
 the exported artifact.
+
+The second output is the profiler's own trace. ``host_span(name, **args)``
+marks an engine's host phases (pad, H2D, launch, wait, fetch, deliver) as
+``jax.profiler.TraceAnnotation``s while a profiler session captures, so
+they land on the ``/host:`` plane of the same ``.xplane.pb`` as the
+device's ops, on its clock, each with its ``args`` as stats.
+``install_gc_spans`` adds a process-level ``host.gc`` span per garbage
+collection there. With no session capturing, a span costs one check and
+returns a shared null context. The flight recorder keeps per-request
+timelines for the operator's Perfetto export; ``host_span`` places the
+engine's host time against the device's.
 """
 from __future__ import annotations
 
+import contextlib
+import gc
 import json
 import threading
 import time
 from collections import deque
 from typing import Any, Callable, Dict, Iterable, List, Mapping, NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
 
 # span phases, in required timeline order (a request's spans must be a
 # subsequence of this — validate_request_timelines enforces it). LM requests
@@ -230,18 +245,48 @@ class _NullTracer:
 NULL_TRACER = _NullTracer()
 
 
+# -- host spans in the profiler trace ---------------------------------------
+
+_NULL_SPAN = contextlib.nullcontext()
+_capturing = TraceAnnotation.is_enabled  # a profiler session is capturing
+
+
+def host_span(name: str, **args: Any):
+    """A ``TraceAnnotation`` named ``name`` with ``args`` as its stats while
+    a profiler session captures, else the shared null context (entered, it
+    gives None; a live span takes late args by ``set_metadata``)."""
+    if _capturing():
+        return TraceAnnotation(name, **args)
+    return _NULL_SPAN
+
+
+_gc_span = None  # the open host.gc span: collections do not nest
+
+
+def _gc_phase(phase: str, info: dict) -> None:
+    global _gc_span
+    if phase == "start":
+        if _capturing():
+            _gc_span = TraceAnnotation("host.gc", gen=info["generation"])
+            _gc_span.__enter__()
+    elif _gc_span is not None:
+        span, _gc_span = _gc_span, None
+        span.__exit__(None, None, None)
+
+
+def install_gc_spans() -> None:
+    """Mark every garbage collection of this process as a ``host.gc`` span
+    (arg ``gen``) while a profiler captures; idempotent."""
+    if _gc_phase not in gc.callbacks:
+        gc.callbacks.append(_gc_phase)
+
+
 def make_tracer(trace_cfg, clock: Callable[[], float] = time.monotonic,
                 label: str = "engine"):
     """Tracer for a ``TraceConfig`` (configs/base.py): a real ``Tracer``
-    when enabled, the shared ``NULL_TRACER`` otherwise. Engines also flip
-    the kernel-annotation flag here so device profiles carry kernel-level
-    names (kernels/ops.py) without every engine repeating the wiring."""
+    when enabled, the shared ``NULL_TRACER`` otherwise."""
     if trace_cfg is None or not trace_cfg.enable:
         return NULL_TRACER
-    if trace_cfg.annotate_kernels:
-        from repro.kernels import ops
-
-        ops.set_kernel_annotations(True)
     return Tracer(capacity=trace_cfg.capacity, clock=clock, label=label)
 
 

@@ -18,16 +18,24 @@ when a batch is *retired* — when the in-flight window is full or at drain.
 Batch shapes are quantized to the ``batch_buckets`` ladder (pad rows of
 zeros), so the engine compiles exactly ``len(batch_buckets)`` programs and
 never re-traces at serving time; call ``warmup()`` to move all compiles out
-of the measured path.
+of the measured path. A launch that compiles anyway counts in the
+``retraces`` counter.
+
+Host phases (serving/trace.py ``host_span``, each with ``batch=<dispatch
+sequence number>``): ``engine.pad`` (bucket buffer and row copies; ``b``,
+``n``), ``engine.h2d`` (the input transfer), ``engine.launch`` (the
+classify call up to its futures; ``retrace``), ``engine.wait`` (the host
+blocked on the batch's classes), ``engine.fetch`` (probabilities and
+routed-token counts to host) and ``engine.deliver`` (metrics, result fill,
+callbacks).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from collections import deque
-from typing import Callable, List, NamedTuple, Optional, Sequence
-
-import contextlib
+from typing import Callable, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -41,7 +49,7 @@ from repro.serving.engine import serving_config
 from repro.serving.events import EventLog
 from repro.serving.metrics import EngineMetrics
 from repro.serving.scheduler import MicroBatcher
-from repro.serving.trace import make_tracer
+from repro.serving.trace import host_span, install_gc_spans, make_tracer
 
 
 @dataclasses.dataclass
@@ -74,11 +82,16 @@ class VisionRequest:
         return self.classes is not None
 
 
-class _InFlight(NamedTuple):
+@dataclasses.dataclass
+class _InFlight:
+    seq: int  # dispatch sequence number (the host spans' ``batch``)
     reqs: tuple  # the real requests in this device batch
     pad_to: int  # padded batch size actually dispatched
     out: dict  # device arrays from classify (not yet synchronized)
     dispatched_at: float
+    # when the host first saw the results ready: _head_ready read true or
+    # the blocking wait returned
+    ready_at: Optional[float] = None
 
 
 class VisionEngine:
@@ -136,6 +149,12 @@ class VisionEngine:
             self.metrics.expert_health = self.expert_health
         self.max_inflight = max(1, int(max_inflight))
         self._inflight: deque = deque()
+        self._seq = 0  # batches dispatched
+        # ready time of the last retired batch: the next one's device
+        # work starts no earlier (one chip runs its batches in order)
+        self._last_ready = float("-inf")
+        self.metrics.inc("retraces", 0)  # exported as 0 until one happens
+        install_gc_spans()
         self.mesh = mesh
         self._ep = (cfg.moe is not None
                     and cfg.moe.moe_exec == "expert_parallel")
@@ -345,10 +364,15 @@ class VisionEngine:
     def _head_ready(self) -> bool:
         """Whether the oldest in-flight batch's device work has finished —
         retiring it then stamps request latency at actual completion, not
-        at the next forced sync (open-loop percentiles stay honest)."""
-        head = self._inflight[0].out["classes"]
-        is_ready = getattr(head, "is_ready", None)
-        return bool(is_ready()) if is_ready is not None else False
+        at the next forced sync (open-loop percentiles stay honest). The
+        first true reading stamps the batch's ``ready_at``."""
+        head = self._inflight[0]
+        if head.ready_at is None:
+            is_ready = getattr(head.out["classes"], "is_ready", None)
+            if is_ready is None or not is_ready():
+                return False
+            head.ready_at = self._clock()
+        return True
 
     def _dispatch_ready(self) -> None:
         while len(self._inflight) < self.max_inflight:
@@ -356,10 +380,14 @@ class VisionEngine:
             if batch is None:
                 return
             reqs = batch.items
-            x = np.zeros((batch.pad_to, self.n_patches, vit.PATCH_DIM),
-                         np.float32)
-            for i, r in enumerate(reqs):
-                x[i] = r.patches
+            seq = self._seq
+            self._seq += 1
+            with host_span("engine.pad", batch=seq, b=batch.pad_to,
+                           n=len(reqs)):
+                x = np.zeros((batch.pad_to, self.n_patches, vit.PATCH_DIM),
+                             np.float32)
+                for i, r in enumerate(reqs):
+                    x[i] = r.patches
             t0 = self._clock()
             for r in reqs:
                 # per-request admission wait measured from the submitted_at
@@ -370,9 +398,20 @@ class VisionEngine:
                 if self.tracer.enabled:
                     self.tracer.transition(r.trace_id, "queue", "infer",
                                            t=t0, pad_to=batch.pad_to)
+            with host_span("engine.h2d", batch=seq):
+                xd = jnp.asarray(x)
             # async dispatch: returns device futures; nothing blocks here
-            out = self._classify(self.params, jnp.asarray(x))
-            self._inflight.append(_InFlight(reqs, batch.pad_to, out, t0))
+            with host_span("engine.launch", batch=seq) as span:
+                compiled = self._lowerable._cache_size()
+                out = self._classify(self.params, xd)
+                # a launch that grew the program's cache compiled
+                retrace = self._lowerable._cache_size() > compiled
+                if span is not None:
+                    span.set_metadata(retrace=int(retrace))
+            if retrace:
+                self.metrics.inc("retraces")
+            self._inflight.append(
+                _InFlight(seq, reqs, batch.pad_to, out, t0))
             self.metrics.inc("batches")
             self.metrics.inc("padded_frames", batch.pad_to - len(reqs))
             # padding-waste observability in *token* units, comparable with
@@ -385,25 +424,42 @@ class VisionEngine:
 
     def _retire_one(self) -> None:
         ent = self._inflight.popleft()
-        classes = np.asarray(ent.out["classes"])  # synchronizes the batch
-        probs = np.asarray(ent.out["probs"])
-        now = self._clock()
-        self.metrics.batch_latency.record(now - ent.dispatched_at)
+        with host_span("engine.wait", batch=ent.seq):
+            classes = np.asarray(ent.out["classes"])  # synchronizes the batch
+        if ent.ready_at is None:
+            ent.ready_at = self._clock()
+        with host_span("engine.fetch", batch=ent.seq):
+            probs = np.asarray(ent.out["probs"])
+            # the answers are whole: request latency ends here, before the
+            # routed-token counts (metrics only) come over
+            now = self._clock()
+            et = ent.out.get("expert_tokens")
+            et = np.asarray(et) if et is not None and et.size else None
+        with host_span("engine.deliver", batch=ent.seq):
+            self._deliver(ent, now, classes, probs, et)
+
+    def _deliver(self, ent: _InFlight, now: float, classes, probs,
+                 et) -> None:
+        # the batch's estimated device interval: its work starts when it
+        # was dispatched or when the batch before it finished, whichever
+        # is later, and ends when the host first saw it ready
+        start = max(ent.dispatched_at, self._last_ready)
+        self._last_ready = ent.ready_at
+        step_s = ent.ready_at - start
+        self.metrics.batch_latency.record(step_s)
         trace = self.tracer.enabled
         if self._step_times:
             # per-bucket step latency, keyed like the autotune/program-key
             # namespace so cluster snapshots read as one schema
-            self.metrics.record_step(f"classify|b={ent.pad_to}",
-                                     now - ent.dispatched_at)
+            self.metrics.record_step(f"classify|b={ent.pad_to}", step_s)
         if trace:
             self.tracer.record_span(f"classify|b={ent.pad_to}",
-                                    ent.dispatched_at, now,
+                                    start, ent.ready_at,
                                     n=len(ent.reqs), pad_to=ent.pad_to)
-        et = ent.out.get("expert_tokens")
-        if et is not None and et.size:
+        if et is not None:
             # NB: includes the pad rows' routed tokens — interpret together
             # with counters["padded_frames"] (DESIGN.md section 6)
-            self.metrics.add_expert_tokens(np.asarray(et))
+            self.metrics.add_expert_tokens(et)
         for i, req in enumerate(ent.reqs):
             if req.evicted or req.status != "pending":
                 # evicted mid-flight (the cluster owns it) or a duplicate

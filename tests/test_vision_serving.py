@@ -151,3 +151,172 @@ def test_plain_vit_family_serves_without_expert_metrics():
     eng = _serve(cfg, params, reqs, batch_buckets=(1, 2), max_wait_s=0.0)
     assert eng.metrics.snapshot()["expert_tokens"] == []
     assert all(r.done for r in reqs)
+
+
+# -- host-phase spans, retraces and step timing -----------------------------
+
+LEAF_SPANS = ("engine.pad", "engine.h2d", "engine.launch", "engine.wait",
+              "engine.fetch", "engine.deliver")
+
+
+def test_host_span_is_the_shared_null_context_without_a_profiler():
+    from repro.serving.trace import host_span
+
+    a, b = host_span("engine.pad", batch=0, b=4, n=1), host_span("x")
+    assert a is b
+    with a as span:
+        assert span is None
+
+
+def _host_events(log_dir):
+    """(name, start_ns, end_ns, stats) of the engine's spans in a trace."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True))[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("engine."):
+                    out.append((e.name, e.start_ns, e.end_ns,
+                                dict(e.stats)))
+    return out
+
+
+def test_engine_host_spans_land_in_the_profiler_trace(moe_vit_trees,
+                                                      tmp_path):
+    """Every batch leaves each leaf span once on the trace's host plane,
+    with its dispatch number; pad, H2D and launch follow each other; the
+    launch after a cleared compile cache carries retrace=1 and counts."""
+    cfg, params, _, _ = moe_vit_trees
+    eng = VisionEngine(cfg, params, batch_buckets=(1, 4), max_wait_s=0.0)
+    eng.warmup()
+    assert eng.metrics.counters.get("retraces", 0) == 0
+    reqs = synth_requests(cfg, 6, seed=4)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for r in reqs[:5]:
+            eng.submit(r)
+        eng.flush()  # one batch of 4, one of 1
+        assert eng.metrics.counters.get("retraces", 0) == 0
+        jax.clear_caches()  # no bucket is warm any more
+        eng.submit(reqs[5])
+        eng.flush()
+    finally:
+        jax.profiler.stop_trace()
+    assert all(r.done for r in reqs)
+    assert eng.metrics.counters["retraces"] == 1
+    events = _host_events(tmp_path)
+    by = {}
+    for name, s, e, stats in events:
+        by.setdefault(stats["batch"], {}).setdefault(name, []).append(
+            (s, e, stats))
+    assert sorted(by) == [0, 1, 2]
+    for batch, spans in by.items():
+        assert sorted(spans) == sorted(LEAF_SPANS), batch
+        assert all(len(v) == 1 for v in spans.values()), batch
+        (pad,), (h2d,), (launch,) = (spans["engine.pad"],
+                                     spans["engine.h2d"],
+                                     spans["engine.launch"])
+        assert pad[1] <= h2d[0] and h2d[1] <= launch[0]
+        assert launch[2]["retrace"] == (1 if batch == 2 else 0)
+    assert [by[i]["engine.pad"][0][2]["b"] for i in range(3)] == [4, 1, 1]
+    assert [by[i]["engine.pad"][0][2]["n"] for i in range(3)] == [4, 1, 1]
+
+
+class _FakeClock:
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        return self.t
+
+
+class _FakeResult:
+    """A device result that is ready at ``done_at`` on the fake clock;
+    reading it blocks (moves the clock) until then."""
+
+    def __init__(self, clock, done_at, value):
+        self.clock, self.done_at, self.value = clock, done_at, value
+
+    def is_ready(self):
+        return self.clock.t >= self.done_at
+
+    def __array__(self, dtype=None, copy=None):
+        self.clock.t = max(self.clock.t, self.done_at)
+        return self.value
+
+
+def test_step_timing_covers_each_batchs_device_interval(moe_vit_trees):
+    """With two batches in flight, a batch's step runs from its dispatch or
+    the previous batch's ready time, whichever is later, to its own ready
+    time (first is_ready, or the blocking wait's return) — one device step,
+    not the dispatch-to-retire window that spans two."""
+    from repro.configs.base import TraceConfig
+
+    cfg, params, _, _ = moe_vit_trees
+    clock = _FakeClock()
+    eng = VisionEngine(cfg.replace(trace=TraceConfig(enable=True)), params,
+                       batch_buckets=(2,), max_wait_s=0.0, max_inflight=2,
+                       clock=clock)
+    done_at = iter([10.0, 20.0, 30.0])  # one chip: the batches in order
+
+    def classify(prm, x):
+        t = next(done_at)
+        clock.t += 1.0  # the launch itself takes host time
+        b = x.shape[0]
+        return {"classes": _FakeResult(clock, t, np.zeros((b, 5), np.int32)),
+                "probs": _FakeResult(clock, t, np.zeros((b, 5), np.float32))}
+
+    eng._classify = classify
+    reqs = synth_requests(cfg, 6, seed=2)
+    for r in reqs[:4]:
+        eng.submit(r)
+    eng.step()  # dispatches A at t=0 and B at t=1
+    clock.t = 12.0
+    for r in reqs[4:]:
+        eng.submit(r)
+    eng.step()  # retires A (ready seen at 12), dispatches C at t=12
+    clock.t = 14.0
+    eng.step()  # window full: waits for B until 20
+    eng.flush()  # waits for C until 30
+    assert all(r.done for r in reqs)
+    steps = [(s.t0, s.t1) for s in eng.tracer.recorder.spans()
+             if s.kind == "step"]
+    assert steps == [(0.0, 12.0), (12.0, 20.0), (20.0, 30.0)]
+    for tracker in (eng.metrics.step_latency["classify|b=2"],
+                    eng.metrics.batch_latency):
+        assert len(tracker) == 3
+        assert tracker.percentile(50) == pytest.approx(10.0)
+        assert tracker.snapshot()["max"] == pytest.approx(12e3)
+
+
+def test_garbage_collections_are_host_spans_while_a_profiler_captures(
+        tmp_path):
+    import gc
+    import glob
+
+    from jax.profiler import ProfileData
+
+    from repro.serving.trace import install_gc_spans
+
+    install_gc_spans()
+    install_gc_spans()  # idempotent: one callback per process
+    assert sum(cb.__name__ == "_gc_phase" for cb in gc.callbacks) == 1
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        gc.collect(1)
+    finally:
+        jax.profiler.stop_trace()
+    gc.collect()  # after the session: no span, nothing left open
+    path = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)[0]
+    gens = [dict(e.stats)["gen"]
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name == "host.gc"]
+    assert 1 in gens
