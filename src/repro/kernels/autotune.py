@@ -50,12 +50,13 @@ from repro.kernels.quant_attention import legal_attn_blocks
 
 # Bumped when a kernel's tiling/legality logic changes (the sublane/lane
 # clamp-rounding fix shipped as version 2; nibble-packed int4 weights and
-# the ``pk`` key facet shipped as grouped_matmul version 3): entries swept
-# against an older kernel are dropped at load so a tuned table can never
-# pin obsolete tiles.
+# the ``pk`` key facet shipped as grouped_matmul version 3; the one-visit
+# 4-bit schedule as streaming_attention version 3): entries swept against an
+# older kernel are dropped at load so a tuned table can never pin obsolete
+# tiles.
 KERNEL_VERSIONS: Dict[str, int] = {
     "grouped_matmul": 3,
-    "streaming_attention": 2,
+    "streaming_attention": 3,
 }
 TABLE_VERSION = 1
 
@@ -69,6 +70,18 @@ _ATTN_BLOCK_Q = (32, 64, 128, 256)
 _ATTN_BLOCK_K = (128, 256, 512)
 
 _VMEM_BUDGET = 12 * 1024 * 1024  # leave headroom under the ~16 MB/core VMEM
+
+
+def attn_default(Sq: int, Sk: int) -> Tuple[int, int]:
+    """Untuned (block_q, block_k) for one attention shape: ``ATTN_DEFAULT``,
+    except that where one default K tile holds the whole key row the Q tile
+    widens to the whole query length, up to the grid's largest Q tile. The
+    4-bit kernel then takes its one-visit schedule in as few grid steps as
+    the rows allow: one per (image, head) for a 197-token ViT row."""
+    bq, bk = ATTN_DEFAULT
+    if Sk <= bk:
+        bq = max(bq, min(Sq, _ATTN_BLOCK_Q[-1]))
+    return bq, bk
 
 
 # ---------------------------------------------------------------------------
@@ -193,7 +206,7 @@ def attn_candidates(req: TuneRequest) -> List[Tuple[int, int]]:
     qdt, kdt = jnp.dtype(req.get("qdt")), jnp.dtype(req.get("kdt"))
     out: List[Tuple[int, int]] = []
     seen = set()
-    for bq, bk in [ATTN_DEFAULT] + [
+    for bq, bk in [attn_default(Sq, Sk)] + [
         (q, k) for q in _ATTN_BLOCK_Q for k in _ATTN_BLOCK_K
     ]:
         eff = legal_attn_blocks(bq, bk, Sq, Sk, qdt)
@@ -397,12 +410,13 @@ def gmm_blocks(T: int, G: int, Din: int, Dout: int, *, x_dtype, w_dtype,
 def attn_blocks(B: int, H: int, KVH: int, hd: int, Sq: int, Sk: int, *,
                 causal: bool, quant_bits: int, scaled: bool,
                 q_dtype, k_dtype, local_window: int = 0) -> Tuple[int, int]:
-    """Tile config for one streaming_attention dispatch (see gmm_blocks)."""
+    """Tile config for one streaming_attention dispatch (see gmm_blocks;
+    the default is ``attn_default``)."""
     req = attn_request(B, H, KVH, hd, Sq, Sk, causal=causal,
                        quant_bits=quant_bits, scaled=scaled,
                        q_dtype=q_dtype, k_dtype=k_dtype,
                        local_window=local_window)
-    return _resolve(req, ATTN_DEFAULT)
+    return _resolve(req, attn_default(Sq, Sk))
 
 
 # ---------------------------------------------------------------------------

@@ -7,14 +7,22 @@ recip(l) rescale). On TPU that layout IS the flash-attention grid
 decomposition: the grid walks Q blocks (the "PEs"); every grid step streams
 the *same* K/V HBM tiles through VMEM while its Q tile stays resident.
 
-Two execution schedules:
+Three execution schedules, chosen from ``quant_bits`` and the number of K
+tiles alone:
 
-  * quant_bits == 0 — classic online single-pass flash (running max/denom).
-  * quant_bits > 0  — the paper's 3-pass schedule: pass 1 over K computes the
-    exact row max (the log-sqrt2 codes must be taken against the *final* max,
-    as on the FPGA, or the power-of-two grid shifts per block); pass 2
-    computes codes, the exact denominator, and the P.V accumulation; the
-    recip(l) rescale happens once at the flush (the paper's Pass 3 trick).
+  * ``online`` (quant_bits == 0) — classic single-pass flash (running
+    max/denom).
+  * ``two_pass`` (quant_bits > 0, more than one K tile) — the paper's
+    3-pass schedule: pass 1 over K computes the exact row max (the
+    log-sqrt2 codes must be taken against the *final* max, as on the FPGA,
+    or the power-of-two grid shifts per block); pass 2 computes codes, the
+    exact denominator, and the P.V accumulation; the recip(l) rescale
+    happens once at the flush (the paper's Pass 3 trick).
+  * ``one_visit`` (quant_bits > 0, one K tile holds the whole key row) —
+    the tile's row max *is* the final max, so passes 1-3 fuse into one grid
+    step per Q tile: QK^T once, then pass 2's arithmetic in pass 2's order,
+    and acc / l written out directly. Same values as ``two_pass``, half the
+    grid steps and one score matmul instead of two (a 197-token ViT row).
 
 The log-sqrt2 quantizer (Eqs. 17-21) is fused in affine-code form:
 codes = clip(round(-2 log2(e) (s - m)), 0, 2^b - 1) — identical math to
@@ -28,6 +36,7 @@ per-position dequant scales, and a per-batch valid length (decode fill level).
 """
 from __future__ import annotations
 
+import collections
 import functools
 import math
 from typing import Optional, Tuple
@@ -41,6 +50,11 @@ from repro.kernels.expert_linear import LANE, _round_up, _sublane
 
 LOG2E = 1.4426950408889634
 SQRT2M1 = 0.41421356237309515  # sqrt(2) - 1
+
+# Trace-time tally: schedule name -> number of ``streaming_attention``
+# calls traced with it: counted as a program traces, not as it runs, so a
+# call inside a scanned layer body counts once however many layers run it.
+SCHEDULES: collections.Counter = collections.Counter()
 
 
 def legal_attn_blocks(block_q: int, block_k: int, Sq: int, Sk: int,
@@ -68,7 +82,7 @@ def _attn_kernel(
     q_ref,  # [1, 1, bq, hd]
     k_ref,  # [1, 1, bk, hd]
     v_ref,  # [1, 1, bk, hd]
-    *rest,  # (k_scale?, v_scale?, qseg?, kseg?, out, m_s, l_s, acc_s)
+    *rest,  # (k_scale?, v_scale?, qseg?, kseg?, out, m_s?, l_s?, acc_s?)
     causal: bool,
     local_window: int,
     logit_softcap: float,
@@ -78,6 +92,7 @@ def _attn_kernel(
     block_q: int,
     block_k: int,
     n_k: int,
+    schedule: str,
     sm_scale: float,
 ):
     rest = list(rest)
@@ -88,21 +103,15 @@ def _attn_kernel(
     if has_segs:
         qseg_ref, kseg_ref = rest[0], rest[1]
         rest = rest[2:]
-    o_ref, m_s, l_s, acc_s = rest
+    o_ref, *scratch = rest  # one_visit keeps no scratch
 
     b = pl.program_id(0)
     iq = pl.program_id(2)
     ikp = pl.program_id(3)
     nk_total = pl.num_programs(3)
-    two_pass = quant_bits > 0
+    two_pass = schedule == "two_pass"
     phase = ikp // n_k if two_pass else 0
     ik = ikp % n_k if two_pass else ikp
-
-    @pl.when(ikp == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, -1e30)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
 
     q_off = meta_ref[b]
     valid = jnp.minimum(valid_ref[b], jnp.int32(n_k * block_k))
@@ -126,6 +135,68 @@ def _attn_kernel(
         # the q side and -2 on the k side so they can never match.
         mask &= qseg_ref[0][:, None] == kseg_ref[0][None, :]
 
+    # f32 operands contract at full f32 precision. Mosaic's default rounds
+    # them to bf16 in one pass, which moves a score by ~2^-9 of its size and
+    # flips log-sqrt2 codes that sit on a step boundary; bf16 and int8
+    # operands are exact in that one pass.
+    prec = (jax.lax.Precision.HIGHEST
+            if jnp.float32 in (q_ref.dtype, k_ref.dtype, v_ref.dtype)
+            else jax.lax.Precision.DEFAULT)
+
+    def scores():
+        q = q_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), precision=prec,
+            preferred_element_type=jnp.float32,
+        ) * sm_scale  # [bq, bk]
+        if has_scales:
+            s = s * ks_ref[0, 0]  # [1, bk] per-position dequant
+        if logit_softcap > 0.0:
+            s = logit_softcap * jnp.tanh(s / logit_softcap)
+        return jnp.where(mask, s, -jnp.inf)
+
+    def quantized_pv(s, m):
+        # Pass 2 (paper section 4.3): log-sqrt2 codes against the final row
+        # max m [bq, 1]; returns the exact denominator and f_hat.V.
+        v = v_ref[0, 0].astype(jnp.float32)
+        f_exact = jnp.exp(s - m)
+        codes = jnp.clip(
+            jnp.round(-2.0 * LOG2E * (s - m)),
+            0.0,
+            2.0**quant_bits - 1.0,
+        ).astype(jnp.int32)
+        shift = (codes + 1) // 2  # ceil(c / 2)
+        parity = (codes & 1).astype(jnp.float32)
+        f_hat = jnp.exp2(-shift.astype(jnp.float32)) * (
+            1.0 + parity * SQRT2M1
+        )
+        f_hat = jnp.where(mask, f_hat, 0.0)
+        l = jnp.sum(f_exact, axis=1, keepdims=True)
+        if has_scales:
+            f_hat = f_hat * vs_ref[0, 0]
+        return l, jax.lax.dot(
+            f_hat, v, precision=prec, preferred_element_type=jnp.float32
+        )
+
+    if schedule == "one_visit":
+        # The one K tile holds every live key of the row, so its row max is
+        # the final max: passes 1-3 in this one step. A row with no live key
+        # reads m = -1e30, l = 0 and writes 0, as the flush below does.
+        s = scores()
+        m = jnp.maximum(jnp.max(s, axis=1, keepdims=True), -1e30)
+        l, pv = quantized_pv(s, m)
+        o_ref[0, 0, :, :] = (pv / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        return
+
+    m_s, l_s, acc_s = scratch
+
+    @pl.when(ikp == 0)
+    def _init():
+        m_s[...] = jnp.full_like(m_s, -1e30)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+
     # Block-level skip: nothing in this K tile can be visible.
     row0 = q_off + iq * block_q  # first (smallest) q position of the tile
     block_alive = jnp.logical_and(
@@ -137,28 +208,9 @@ def _attn_kernel(
             block_alive, (ik + 1) * block_k > row0 - local_window + 1
         )
 
-    # f32 operands contract at full f32 precision. Mosaic's default rounds
-    # them to bf16 in one pass, which moves a score by ~2^-9 of its size and
-    # flips log-sqrt2 codes that sit on a step boundary; bf16 and int8
-    # operands are exact in that one pass.
-    prec = (jax.lax.Precision.HIGHEST
-            if jnp.float32 in (q_ref.dtype, k_ref.dtype, v_ref.dtype)
-            else jax.lax.Precision.DEFAULT)
-
     @pl.when(block_alive)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), precision=prec,
-            preferred_element_type=jnp.float32,
-        ) * sm_scale  # [bq, bk]
-        if has_scales:
-            s = s * ks_ref[0, 0]  # [1, bk] per-position dequant
-        if logit_softcap > 0.0:
-            s = logit_softcap * jnp.tanh(s / logit_softcap)
-        s = jnp.where(mask, s, -jnp.inf)
-
+        s = scores()
         if two_pass:
             @pl.when(phase == 0)
             def _pass1():
@@ -168,28 +220,9 @@ def _attn_kernel(
 
             @pl.when(phase == 1)
             def _pass2():
-                # Pass 2: log-sqrt2 codes against the final max + exact denom.
-                v = v_ref[0, 0].astype(jnp.float32)
-                m = m_s[:, :1]  # [bq, 1]
-                f_exact = jnp.exp(s - m)
-                codes = jnp.clip(
-                    jnp.round(-2.0 * LOG2E * (s - m)),
-                    0.0,
-                    2.0**quant_bits - 1.0,
-                ).astype(jnp.int32)
-                shift = (codes + 1) // 2  # ceil(c / 2)
-                parity = (codes & 1).astype(jnp.float32)
-                f_hat = jnp.exp2(-shift.astype(jnp.float32)) * (
-                    1.0 + parity * SQRT2M1
-                )
-                f_hat = jnp.where(mask, f_hat, 0.0)
-                l_s[...] += jnp.sum(f_exact, axis=1, keepdims=True)
-                if has_scales:
-                    f_hat = f_hat * vs_ref[0, 0]
-                acc_s[...] += jax.lax.dot(
-                    f_hat, v, precision=prec,
-                    preferred_element_type=jnp.float32,
-                )
+                l_blk, pv = quantized_pv(s, m_s[:, :1])
+                l_s[...] += l_blk
+                acc_s[...] += pv
         else:
             # Online single-pass flash (running max / denominator).
             v = v_ref[0, 0].astype(jnp.float32)
@@ -269,7 +302,12 @@ def streaming_attention(
     )
 
     has_scales = k_scale is not None
-    two_pass = quant_bits > 0
+    if quant_bits <= 0:
+        schedule = "online"
+    else:
+        schedule = "one_visit" if n_k == 1 else "two_pass"
+    SCHEDULES[schedule] += 1
+    two_pass = schedule == "two_pass"
     grid = (B, H, n_q, (2 * n_k) if two_pass else n_k)
 
     # NB: with PrefetchScalarGridSpec, index maps receive
@@ -341,6 +379,7 @@ def streaming_attention(
         block_q=block_q,
         block_k=block_k,
         n_k=n_k,
+        schedule=schedule,
         sm_scale=1.0 / math.sqrt(hd),
     )
 
@@ -353,7 +392,7 @@ def streaming_attention(
             out_specs=pl.BlockSpec(
                 (1, 1, block_q, hd), lambda b, h, iq, ikp, m, vl: (b, h, iq, 0)
             ),
-            scratch_shapes=[
+            scratch_shapes=[] if schedule == "one_visit" else [
                 pltpu.VMEM((block_q, 128), jnp.float32),  # running max
                 pltpu.VMEM((block_q, 128), jnp.float32),  # running denom
                 pltpu.VMEM((block_q, hd), jnp.float32),  # P.V accumulator

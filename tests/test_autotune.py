@@ -78,6 +78,43 @@ def test_candidate_grids_are_legal_and_default_first():
     assert len(set(acands)) == len(acands)
 
 
+def _vit_attn_blocks(Sq, Sk):
+    return autotune.attn_blocks(32, 6, 6, 64, Sq, Sk, causal=False,
+                                quant_bits=4, scaled=False,
+                                q_dtype=jnp.float32, k_dtype=jnp.float32)
+
+
+def test_attention_default_q_tile_covers_a_one_tile_row():
+    """Where one K tile holds the key row, the default Q tile takes the
+    whole query length (one grid step per (image, head) at 197 tokens);
+    longer rows keep ATTN_DEFAULT."""
+    assert legal_attn_blocks(*_vit_attn_blocks(197, 197), 197, 197) == (200, 256)
+    assert legal_attn_blocks(*_vit_attn_blocks(1, 197), 1, 197) == (8, 256)
+    assert _vit_attn_blocks(2048, 256) == (256, 256)  # the grid's largest
+    for Sq, Sk in [(197, 257), (1, 512), (1024, 1024)]:
+        assert _vit_attn_blocks(Sq, Sk) == autotune.ATTN_DEFAULT
+    # the autotuner's baseline candidate is the same default
+    req = autotune.attn_request(32, 6, 6, 64, 197, 197, causal=False,
+                                quant_bits=4, scaled=False,
+                                q_dtype=jnp.float32, k_dtype=jnp.float32)
+    best = autotune.attn_candidates(req)[0]
+    assert legal_attn_blocks(*best, 197, 197) == (200, 256)
+
+
+def test_active_table_overrides_attention_default():
+    req = autotune.attn_request(32, 6, 6, 64, 197, 197, causal=False,
+                                quant_bits=4, scaled=False,
+                                q_dtype=jnp.float32, k_dtype=jnp.float32)
+    table = autotune.TuningTable("cpu")
+    table.put(req.key, (64, 128), None, "override")
+    autotune.activate(table)
+    try:
+        assert _vit_attn_blocks(197, 197) == (64, 128)
+    finally:
+        autotune.deactivate()
+    assert _vit_attn_blocks(197, 197) == (197, 256)
+
+
 # ---------------------------------------------------------------------------
 # Interpret-mode parity across every swept candidate
 # ---------------------------------------------------------------------------

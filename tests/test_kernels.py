@@ -8,11 +8,19 @@ import pytest
 from repro.kernels import ref
 from repro.kernels.expert_linear import grouped_matmul
 from repro.kernels.int8_matmul import int8_matmul
-from repro.kernels.quant_attention import streaming_attention
+from repro.kernels.quant_attention import SCHEDULES, streaming_attention
 
 
 def _t(rng, *shape, dtype=jnp.float32):
     return jnp.asarray(rng.standard_normal(shape), dtype)
+
+
+def _grid(rng, *shape):
+    """Normal draws rounded to a 1/4 grid: a q.k sum over such values (or
+    over int8 keys) is exact in any order, so no log-sqrt2 code flips
+    between a tiled kernel and the one-shot reference. At 197 tokens a
+    plain normal draw leaves some score within an ulp of a code step."""
+    return jnp.round(_t(rng, *shape) * 4.0) / 4.0
 
 
 # ---------------------------------------------------------------------------
@@ -29,22 +37,66 @@ ATTN_CASES = [
     (2, 48, 96, 4, 2, 64, True, 4, 30.0, 16),
     (1, 1, 128, 8, 2, 64, True, 4, 0.0, 0),  # decode
     (3, 17, 33, 2, 2, 16, True, 4, 0.0, 0),  # ragged (padding paths)
+    # Sk > 128: block_k 128 takes the two-pass schedule, 256 the one-visit
+    # (K tiles are lane multiples, so every row above fits one at either)
+    (2, 197, 197, 6, 6, 64, False, 4, 0.0, 0),  # M3ViT-S attention
+    (1, 160, 160, 4, 2, 64, True, 4, 30.0, 48),  # causal prefill
+    (2, 1, 200, 8, 2, 64, True, 4, 0.0, 0),  # decode over a 200-key cache
 ]
 
 
+@pytest.mark.parametrize("block_k", [128, 256])
 @pytest.mark.parametrize(
     "B,Sq,Sk,H,KVH,hd,causal,qb,cap,win", ATTN_CASES
 )
-def test_attention_matches_ref(rng, B, Sq, Sk, H, KVH, hd, causal, qb, cap, win):
-    q, k, v = _t(rng, B, Sq, H, hd), _t(rng, B, Sk, KVH, hd), _t(rng, B, Sk, KVH, hd)
+def test_attention_matches_ref(rng, B, Sq, Sk, H, KVH, hd, causal, qb, cap,
+                               win, block_k):
+    qk = _grid if Sk > 128 else _t  # long rows: see _grid
+    q, k, v = qk(rng, B, Sq, H, hd), qk(rng, B, Sk, KVH, hd), _t(rng, B, Sk, KVH, hd)
     off = Sk - Sq if causal else 0
     valid = jnp.full((B,), Sk, jnp.int32)
     kw = dict(causal=causal, q_offset=off, quant_bits=qb, logit_softcap=cap,
               local_window=win, kv_valid_len=valid)
-    out_k = streaming_attention(q, k, v, block_q=32, block_k=32,
+    out_k = streaming_attention(q, k, v, block_q=32, block_k=block_k,
                                 interpret=True, **kw)
     out_r = ref.flash_attention_ref(q, k, v, **kw)
     np.testing.assert_allclose(out_k, out_r, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("int8_kv", [False, True], ids=["vit", "int8_kv"])
+def test_one_visit_matches_two_pass(rng, int8_kv):
+    """When one K tile holds the key row, the 4-bit kernel fuses the max
+    pass into the codes pass. Its output must be the two-pass schedule's
+    (the same codes against the same exact max) to the last ulp, and both
+    must be the reference's. ``int8_kv``: a cache of 192 keys with per-slot
+    fill levels and dequant scales, a causal chunk at each slot's end."""
+    from repro.models.layers import quantize_kv
+
+    if int8_kv:
+        B, Sq, Sk, H, KVH, hd = 2, 8, 192, 4, 2, 64
+        q = _grid(rng, B, Sq, H, hd)
+        kq, ks = quantize_kv(_t(rng, B, Sk, KVH, hd))
+        vq, vs = quantize_kv(_t(rng, B, Sk, KVH, hd))
+        valid = jnp.asarray([150, 192], jnp.int32)
+        k, v = kq, vq
+        kw = dict(causal=True, q_offset=valid - Sq, quant_bits=4,
+                  k_scale=ks, v_scale=vs, kv_valid_len=valid)
+    else:
+        B, Sq, Sk, H, KVH, hd = 2, 197, 197, 6, 6, 64
+        q, k = _grid(rng, B, Sq, H, hd), _grid(rng, B, Sk, KVH, hd)
+        v = _t(rng, B, Sk, KVH, hd)
+        kw = dict(causal=False, quant_bits=4)
+    out_r = np.asarray(ref.flash_attention_ref(q, k, v, **kw))
+    outs = {}
+    for block_k, schedule in [(256, "one_visit"), (128, "two_pass")]:
+        before = SCHEDULES[schedule]
+        outs[schedule] = np.asarray(streaming_attention(
+            q, k, v, block_q=256, block_k=block_k, interpret=True, **kw))
+        assert SCHEDULES[schedule] == before + 1
+        np.testing.assert_allclose(outs[schedule], out_r, atol=2e-5,
+                                   rtol=2e-5, err_msg=schedule)
+    np.testing.assert_allclose(outs["one_visit"], outs["two_pass"],
+                               atol=1e-6, rtol=0)
 
 
 def test_attention_bf16_inputs(rng):

@@ -81,11 +81,20 @@ def test_grouped_matmul_int4_packed(compile_for_chip):
 
 
 def test_streaming_attention_197_tokens_4bit(compile_for_chip):
-    qkv = ((B, TOKENS, HEADS, HD), jnp.float32)
-    hlo = compile_for_chip(
-        lambda q, k, v: ops.attention(q, k, v, causal=False, quant_bits=4),
-        qkv, qkv, qkv)
-    assert "tpu_custom_call" in hlo
+    """Buckets 8 and 32 at the default tiles: one 200-row Q tile over one
+    256-key tile, the one-visit schedule."""
+    from repro.kernels.quant_attention import SCHEDULES
+
+    for batch in (B, 32):
+        qkv = ((batch, TOKENS, HEADS, HD), jnp.float32)
+        before = SCHEDULES["one_visit"]
+        hlo = compile_for_chip(
+            lambda q, k, v: ops.attention(q, k, v, causal=False,
+                                          quant_bits=4),
+            qkv, qkv, qkv)
+        assert "tpu_custom_call" in hlo
+        assert f"f32[{batch},{HEADS},200,{HD}]" in hlo
+        assert SCHEDULES["one_visit"] == before + 1
 
 
 def test_decode_attention_over_int8_kv_cache(compile_for_chip):
@@ -99,6 +108,25 @@ def test_decode_attention_over_int8_kv_cache(compile_for_chip):
         ((B, 1, HEADS, HD), jnp.float32), kv, kv, scale, scale,
         ((B,), jnp.int32))
     assert "tpu_custom_call" in hlo
+
+
+def test_one_visit_attention_over_int8_kv_cache(compile_for_chip):
+    """4-bit attention of an 8-token chunk over an int8 cache of 256 keys
+    with dequant scales: one K tile, so the one-visit schedule."""
+    from repro.kernels.quant_attention import SCHEDULES
+
+    max_len = 256
+    kv = ((B, max_len, HEADS, HD), jnp.int8)
+    scale = ((B, max_len, HEADS), jnp.float32)
+    before = SCHEDULES["one_visit"]
+    hlo = compile_for_chip(
+        lambda q, k, v, ks, vs, n: ops.attention(
+            q, k, v, causal=True, q_offset=n, quant_bits=4, k_scale=ks,
+            v_scale=vs, kv_valid_len=n + 8),
+        ((B, 8, HEADS, HD), jnp.float32), kv, kv, scale, scale,
+        ((B,), jnp.int32))
+    assert "tpu_custom_call" in hlo
+    assert SCHEDULES["one_visit"] == before + 1
 
 
 @pytest.mark.parametrize("m,k,n", [(B * TOKENS, D, D), (B, D, FF)],
